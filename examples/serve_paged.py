@@ -45,7 +45,7 @@ pages_k = jnp.asarray(rng.normal(size=(P, page, K, hd)), jnp.float32)
 pages_v = jnp.asarray(rng.normal(size=(P, page, K, hd)), jnp.float32)
 q = jnp.asarray(rng.normal(size=(B, H, hd)), jnp.float32)
 
-out = paged_decode(q, pages_k, pages_v, pt, lengths, interpret=True)  # Pallas kernel
+out = paged_decode(q, pages_k, pages_v, pt, lengths)  # Pallas kernel
 ref = paged_decode_reference(q, pages_k, pages_v, pt, lengths)
 print(f"  kernel vs oracle max|Δ| = {float(jnp.max(jnp.abs(out-ref))):.2e}")
 print(f"  page-table bytes per seq: {pt.shape[1]*4} B — the only metadata the scheduler touches")

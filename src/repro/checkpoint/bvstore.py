@@ -33,6 +33,11 @@ from repro.core import DB, DBConfig, KVStore
 CHUNK = 4 << 20  # 4 MiB value chunks (page-aligned batches downstream)
 
 
+def content_hash(buf: bytes) -> str:
+    """The manifest's per-tensor hash: blake2b-128 of the tensor's bytes."""
+    return hashlib.blake2b(buf, digest_size=16).hexdigest()
+
+
 def _leaf_paths(tree) -> list[tuple[str, object]]:
     flat, _ = jax.tree_util.tree_flatten_with_path(tree)
     return [(jax.tree_util.keystr(kp), leaf) for kp, leaf in flat]
@@ -86,7 +91,6 @@ class BVCheckpointStore:
         """Returns {path: (content_hash, src_step)} for incremental chaining —
         src_step is where the chunks PHYSICALLY live (chains of reuse keep
         pointing at the original writer)."""
-        t0 = time.monotonic()
         leaves = _leaf_paths(state)
         manifest = []
         hashes: dict[str, tuple] = {}
@@ -94,7 +98,7 @@ class BVCheckpointStore:
         for path, leaf in leaves:
             arr = np.asarray(jax.device_get(leaf))
             buf = arr.tobytes()
-            h = hashlib.blake2b(buf, digest_size=16).hexdigest()
+            h = content_hash(buf)
             entry = {
                 "path": path,
                 "shape": list(arr.shape),
@@ -124,8 +128,6 @@ class BVCheckpointStore:
         }
         self.db.put(self._meta_key(step), msgpack.packb(meta, use_bin_type=True))
         self.db.flush()
-        save_s = time.monotonic() - t0
-        meta["save_seconds"] = save_s
         return hashes
 
     def _chunk_key(self, step: int, path: str, ci: int) -> bytes:

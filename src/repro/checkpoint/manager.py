@@ -37,6 +37,7 @@ class CheckpointManager:
         self._lock = threading.Lock()
         self.save_count = 0
         self.stall_seconds = 0.0  # time the TRAIN LOOP was blocked
+        self.save_seconds: list[float] = []  # store.save wall time, per save
 
     def maybe_save(self, step: int, state, extra_meta: dict | None = None) -> bool:
         if step % self.interval != 0:
@@ -52,10 +53,12 @@ class CheckpointManager:
 
         def _write():
             prev = self._prev_hashes if self.incremental else None
+            t_save = time.monotonic()
             hashes = self.store.save(step, host_state, extra_meta, prev_hashes=prev)
             with self._lock:
                 self._prev_hashes = hashes
                 self.save_count += 1
+                self.save_seconds.append(time.monotonic() - t_save)
             self._retire(step)
 
         if self.async_save:

@@ -166,38 +166,21 @@ def mesh_context(mesh, rules=None):
 
 
 def shard_map(f, mesh, in_specs, out_specs, axis_names=None, check_vma=True):
-    """Version-portable ``shard_map``.
-
-    jax >= 0.6 exposes ``jax.shard_map(..., axis_names=<manual set>,
-    check_vma=...)``; 0.4.x has ``jax.experimental.shard_map.shard_map``
-    with the complementary ``auto=<non-manual set>`` and ``check_rep``.
-    Model code calls this wrapper with the NEW spelling only.
-    """
-    if hasattr(jax, "shard_map"):
-        kw = {"check_vma": check_vma}
-        if axis_names is not None:
-            kw["axis_names"] = frozenset(axis_names)
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    # 0.4.x's partial-manual mode (auto=...) trips an XLA SPMD-partitioner
-    # CHECK on CPU, so run fully manual: unmentioned axes are replicated per
-    # the in_specs, which is semantically valid (just skips GSPMD
-    # auto-sharding inside the body on the non-manual axes).
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=check_vma
+    """``jax.shard_map`` with ``axis_names`` (the manual axes) as any
+    iterable; ``None`` makes every mesh axis manual."""
+    kw = {} if axis_names is None else {"axis_names": frozenset(axis_names)}
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check_vma, **kw
     )
 
 
 def _bound_axis_names() -> set:
     """Mesh axes currently bound manually (we are tracing inside a
-    ``shard_map``/``pmap`` body over them)."""
-    try:
-        from jax._src import core as _core
+    ``shard_map``/``pmap`` body over them). JAX has no public API for
+    this, hence the private ``jax._src.core`` call."""
+    from jax._src import core as _core
 
-        return set(_core.get_axis_env().axis_sizes)
-    except Exception:
-        return set()
+    return set(_core.get_axis_env().axis_sizes)
 
 
 def constrain(x, axes, rules=None):

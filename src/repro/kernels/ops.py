@@ -1,9 +1,9 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-``use_pallas`` gates kernel vs pure-jnp oracle paths: the CPU container
-(and the 512-device dry-run) uses the jnp path — identical math, identical
-FLOPs — while TPU deployments flip the flag. ``interpret`` runs the kernel
-body in Python on CPU (used by the test sweeps).
+Each wrapper runs its kernel compiled for the device it is traced for;
+only on the CPU backend, which has no Mosaic compiler, does the kernel
+body run in interpret mode. There is no jnp fallback: the pure-jnp
+oracles in :mod:`repro.kernels.ref` are test references only.
 """
 from __future__ import annotations
 
@@ -11,38 +11,33 @@ from functools import partial
 
 import jax
 
-from . import ref
 from .decode_attention import paged_decode_attention
 from .flash_attention import flash_attention
 from .rglru_scan import rglru_pallas
 from .ssd_scan import ssd_chunked_pallas
 
 
-@partial(jax.jit, static_argnames=("causal", "window", "use_pallas", "interpret"))
-def attention(q, k, v, *, causal=True, window=None, use_pallas=False, interpret=False):
-    if use_pallas or interpret:
-        return flash_attention(q, k, v, causal=causal, window=window, interpret=interpret)
-    return ref.mha_reference(q, k, v, causal=causal, window=window)
+def _interpret() -> bool:
+    return jax.default_backend() == "cpu"
 
 
-@partial(jax.jit, static_argnames=("use_pallas", "interpret"))
-def paged_decode(q, pages_k, pages_v, page_table, lengths, *, use_pallas=False, interpret=False):
-    if use_pallas or interpret:
-        return paged_decode_attention(
-            q, pages_k, pages_v, page_table, lengths, interpret=interpret
-        )
-    return ref.paged_decode_reference(q, pages_k, pages_v, page_table, lengths)
+@partial(jax.jit, static_argnames=("causal", "window"))
+def attention(q, k, v, *, causal=True, window=None):
+    return flash_attention(q, k, v, causal=causal, window=window, interpret=_interpret())
 
 
-@partial(jax.jit, static_argnames=("chunk", "use_pallas", "interpret"))
-def ssd_scan(x, dA, B_, C_, chunk, *, use_pallas=False, interpret=False):
-    if use_pallas or interpret:
-        return ssd_chunked_pallas(x, dA, B_, C_, chunk, interpret=interpret)
-    return ref.ssd_chunk_reference(x, dA, B_, C_)
+@jax.jit
+def paged_decode(q, pages_k, pages_v, page_table, lengths):
+    return paged_decode_attention(
+        q, pages_k, pages_v, page_table, lengths, interpret=_interpret()
+    )
 
 
-@partial(jax.jit, static_argnames=("use_pallas", "interpret"))
-def rglru(x, r, i, lam, h0=None, *, use_pallas=False, interpret=False):
-    if use_pallas or interpret:
-        return rglru_pallas(x, r, i, lam, h0, interpret=interpret)
-    return ref.rglru_reference(x, r, i, lam, h0)
+@partial(jax.jit, static_argnames=("chunk",))
+def ssd_scan(x, dA, B_, C_, chunk):
+    return ssd_chunked_pallas(x, dA, B_, C_, chunk, interpret=_interpret())
+
+
+@jax.jit
+def rglru(x, r, i, lam, h0=None):
+    return rglru_pallas(x, r, i, lam, h0, interpret=_interpret())

@@ -12,7 +12,10 @@ implement (i) in two phases around the host-side scan for (ii):
   phase B (``ssd_chunk_output``): y = y_diag + (C ⊙ exp(cumA)) H_inᵀ.
 
 VMEM per program ≈ cs·(p + 2n + cs) fp32 ≈ 0.7 MiB at cs=256, p=64, n=128.
-Validated in interpret mode against ``ref.ssd_chunk_reference``.
+``dA`` is laid out (b, nc, h, 1, cs) so its (1, cs) block spans full dims
+and meets the TPU's (8, 128) block tiling at any head count. Validated in
+interpret mode against ``ref.ssd_chunk_reference``; compiled for v5e in
+tests/test_tpu_compile.py.
 """
 from __future__ import annotations
 
@@ -24,45 +27,62 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+# fp32 operands are contracted at fp32, not in one bf16 pass
+_F32 = jax.lax.Precision.HIGHEST
+
+
+def _cumsum_col(dA):
+    """dA (1, cs) row → its inclusive prefix sums as a (cs, 1) column.
+
+    Masked lane reductions over iota masks: no cumsum or transpose, which
+    Mosaic does not lower for these shapes, and exact fp32 adds."""
+    cs = dA.shape[1]
+    ii = jax.lax.broadcasted_iota(jnp.int32, (cs, cs), 0)
+    kk = jax.lax.broadcasted_iota(jnp.int32, (cs, cs), 1)
+    row = jnp.broadcast_to(dA, (cs, cs))
+    return jnp.sum(jnp.where(kk <= ii, row, 0.0), axis=1, keepdims=True)
 
 
 def _states_kernel(x_ref, dA_ref, b_ref, c_ref, y_ref, s_ref, *, chunk: int):
-    # x (1,1,1,cs,p); dA (1,1,1,cs); b/c (1,1,cs,n); y (1,1,1,cs,p); s (1,1,1,p,n)
+    # x (1,1,1,cs,p); dA (1,1,1,1,cs); b/c (1,1,cs,n); y (1,1,1,cs,p); s (1,1,1,p,n)
     x = x_ref[0, 0, 0].astype(jnp.float32)  # (cs, p)
-    dA = dA_ref[0, 0, 0].astype(jnp.float32)  # (cs,)
+    dA = dA_ref[0, 0, 0].astype(jnp.float32)  # (1, cs)
     B = b_ref[0, 0].astype(jnp.float32)  # (cs, n)
     C = c_ref[0, 0].astype(jnp.float32)  # (cs, n)
 
-    cum = jnp.cumsum(dA)  # (cs,)
-    seg = cum[:, None] - cum[None, :]  # (i, j)
-    ii = jax.lax.broadcasted_iota(jnp.int32, seg.shape, 0)
-    jj = jax.lax.broadcasted_iota(jnp.int32, seg.shape, 1)
+    ii = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    jj = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    cum_col = _cumsum_col(dA)  # (cs, 1): cum[i] down the rows
+    # cum[j] along the lanes: the same prefix sums, read off the diagonal
+    diag = jnp.where(ii == jj, jnp.broadcast_to(cum_col, (chunk, chunk)), 0.0)
+    cum_row = jnp.sum(diag, axis=0, keepdims=True)  # (1, cs)
+    seg = cum_col - cum_row  # (i, j)
     L = jnp.exp(jnp.where(ii >= jj, seg, NEG_INF))
 
     CB = jax.lax.dot_general(
-        C, B, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        C, B, (((1,), (1,)), ((), ())), precision=_F32, preferred_element_type=jnp.float32
     )  # (i, j)
     scores = CB * L
     y_ref[0, 0, 0, ...] = jax.lax.dot_general(
-        scores, x, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        scores, x, (((1,), (0,)), ((), ())), precision=_F32, preferred_element_type=jnp.float32
     ).astype(y_ref.dtype)
 
-    decay = jnp.exp(cum[-1] - cum)  # (cs,)
-    Bd = B * decay[:, None]  # (cs, n)
+    total = jnp.sum(dA, axis=1, keepdims=True)  # (1, 1) = cum[-1]
+    decay = jnp.exp(total - cum_col)  # (cs, 1)
+    Bd = B * decay  # (cs, n)
     s_ref[0, 0, 0, ...] = jax.lax.dot_general(
-        x, Bd, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        x, Bd, (((0,), (0,)), ((), ())), precision=_F32, preferred_element_type=jnp.float32
     ).astype(s_ref.dtype)  # (p, n)
 
 
 def _output_kernel(ydiag_ref, dA_ref, c_ref, hin_ref, y_ref):
     ydiag = ydiag_ref[0, 0, 0].astype(jnp.float32)  # (cs, p)
-    dA = dA_ref[0, 0, 0].astype(jnp.float32)  # (cs,)
+    dA = dA_ref[0, 0, 0].astype(jnp.float32)  # (1, cs)
     C = c_ref[0, 0].astype(jnp.float32)  # (cs, n)
     Hin = hin_ref[0, 0, 0].astype(jnp.float32)  # (p, n)
-    cum = jnp.cumsum(dA)
-    Cd = C * jnp.exp(cum)[:, None]  # (cs, n)
+    Cd = C * jnp.exp(_cumsum_col(dA))  # (cs, n)
     y_off = jax.lax.dot_general(
-        Cd, Hin, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        Cd, Hin, (((1,), (1,)), ((), ())), precision=_F32, preferred_element_type=jnp.float32
     )  # (cs, p)
     y_ref[0, 0, 0, ...] = (ydiag + y_off).astype(y_ref.dtype)
 
@@ -77,7 +97,7 @@ def ssd_chunked_pallas(x, dA, B_, C_, chunk: int, *, interpret: bool = False):
     nc = t // chunk
 
     xc = x.reshape(b, nc, chunk, h, p).transpose(0, 1, 3, 2, 4)  # (b,nc,h,cs,p)
-    dAc = dA.reshape(b, nc, chunk, h).transpose(0, 1, 3, 2)  # (b,nc,h,cs)
+    dAc = dA.reshape(b, nc, chunk, h).transpose(0, 1, 3, 2)[:, :, :, None, :]  # (b,nc,h,1,cs)
     Bc = B_.reshape(b, nc, chunk, n)  # (b,nc,cs,n)
     Cc = C_.reshape(b, nc, chunk, n)
 
@@ -87,7 +107,7 @@ def ssd_chunked_pallas(x, dA, B_, C_, chunk: int, *, interpret: bool = False):
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, 1, chunk, p), lambda i, c, j: (i, c, j, 0, 0)),
-            pl.BlockSpec((1, 1, 1, chunk), lambda i, c, j: (i, c, j, 0)),
+            pl.BlockSpec((1, 1, 1, 1, chunk), lambda i, c, j: (i, c, j, 0, 0)),
             pl.BlockSpec((1, 1, chunk, n), lambda i, c, j: (i, c, 0, 0)),
             pl.BlockSpec((1, 1, chunk, n), lambda i, c, j: (i, c, 0, 0)),
         ],
@@ -103,7 +123,7 @@ def ssd_chunked_pallas(x, dA, B_, C_, chunk: int, *, interpret: bool = False):
     )(xc, dAc, Bc, Cc)
 
     # inter-chunk recurrence (tiny): H_{c} entering chunk c
-    chunk_decay = jnp.exp(dAc.astype(jnp.float32).sum(axis=3))  # (b,nc,h)
+    chunk_decay = jnp.exp(dAc.astype(jnp.float32).sum(axis=(3, 4)))  # (b,nc,h)
 
     def step(H, inp):
         S_c, dec_c = inp
@@ -119,7 +139,7 @@ def ssd_chunked_pallas(x, dA, B_, C_, chunk: int, *, interpret: bool = False):
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, 1, chunk, p), lambda i, c, j: (i, c, j, 0, 0)),
-            pl.BlockSpec((1, 1, 1, chunk), lambda i, c, j: (i, c, j, 0)),
+            pl.BlockSpec((1, 1, 1, 1, chunk), lambda i, c, j: (i, c, j, 0, 0)),
             pl.BlockSpec((1, 1, chunk, n), lambda i, c, j: (i, c, 0, 0)),
             pl.BlockSpec((1, 1, 1, p, n), lambda i, c, j: (i, c, j, 0, 0)),
         ],

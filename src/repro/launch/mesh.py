@@ -11,12 +11,7 @@ import jax
 
 
 def _make_mesh(shape, axes):
-    # jax >= 0.5 wants explicit Auto axis types; 0.4.x has no AxisType and
-    # every axis is GSPMD-auto already.
-    if hasattr(jax.sharding, "AxisType"):
-        axis_types = (jax.sharding.AxisType.Auto,) * len(axes)
-        return jax.make_mesh(shape, axes, axis_types=axis_types)
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

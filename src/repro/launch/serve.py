@@ -11,8 +11,9 @@ import jax
 import numpy as np
 
 from repro.configs import get_config
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import build_model
-from repro.serving.engine import Request, ServingEngine
+from repro.serving.engine import Request, ServingEngine, bf16_init
 
 
 def main() -> None:
@@ -24,13 +25,12 @@ def main() -> None:
     ap.add_argument("--max-new", type=int, default=16)
     ap.add_argument("--max-batch", type=int, default=4)
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
-    model = build_model(cfg)
-    params = model.init(jax.random.key(0))
-    params = jax.tree.map(lambda p: p.astype("bfloat16") if p.dtype == np.float32 else p, params)
+    params = bf16_init(build_model(cfg))(jax.random.key(0))
 
     engine = ServingEngine(cfg, params, max_batch=args.max_batch, max_len=256)
     rng = np.random.default_rng(0)

@@ -12,6 +12,7 @@ from __future__ import annotations
 import argparse
 
 from repro.configs import get_config
+from repro.launch.compile_cache import use_compile_cache
 from repro.training.optimizer import OptimizerConfig
 from repro.training.train_step import TrainConfig
 from repro.training.trainer import Trainer, TrainerConfig
@@ -31,6 +32,7 @@ def main() -> None:
     ap.add_argument("--accum", type=int, default=1)
     ap.add_argument("--d-model", type=int, default=0, help="override reduced d_model")
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

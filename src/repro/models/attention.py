@@ -1,10 +1,6 @@
 """Grouped-query attention: train/prefill (optionally chunked + windowed) and
 single-token decode against a KV cache.
 
-The pure-jnp path here is the dry-run/oracle implementation; the Pallas
-flash kernels in :mod:`repro.kernels` are drop-in replacements gated by
-``use_pallas`` (see kernels/ops.py).
-
 Shapes: q (B, T, H, D); k/v (B, S, K, D) with H = K·G (GQA groups).
 """
 from __future__ import annotations

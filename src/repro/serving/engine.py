@@ -36,6 +36,20 @@ class Request:
     done_at: float | None = None
 
 
+def bf16_init(model):
+    """``key -> params``: the model's initialisation with every fp32
+    parameter cast to bf16, jitted as one program so the fp32 parameters
+    never sit whole on the device."""
+
+    def init(key):
+        params = model.init(key)
+        return jax.tree.map(
+            lambda p: p.astype(jnp.bfloat16) if p.dtype == jnp.float32 else p, params
+        )
+
+    return jax.jit(init)
+
+
 class ServingEngine:
     def __init__(self, model_cfg, params, max_batch: int = 8, max_len: int = 512,
                  page_size: int = 64):

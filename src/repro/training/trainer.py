@@ -72,11 +72,16 @@ class Trainer:
         self.metrics_log: list[dict] = []
 
     # ------------------------------------------------------------------
+    def _state_shardings(self, template):
+        """NamedShardings of the train state on the trainer's mesh."""
+        axes = state_axes(self.model, self.tcfg.train.opt, template)
+        return tree_shardings(self.mesh, template, axes)
+
     def _init_or_restore(self):
         latest = self.store.latest_step()
-        template = jax.eval_shape(
-            lambda: init_state(self.model, jax.random.key(self.tcfg.seed), self.tcfg.train.opt)
-        )
+        init = lambda key: init_state(self.model, key, self.tcfg.train.opt)
+        key = jax.random.key(self.tcfg.seed)
+        template = jax.eval_shape(init, key)
         if latest is not None:
             if self.mesh is not None:
                 axes = state_axes(self.model, self.tcfg.train.opt, template)
@@ -86,7 +91,10 @@ class Trainer:
                 self.state = jax.tree.map(jax.numpy.asarray, self.state)
             self.pipeline.load_state_dict(meta["extra"]["pipeline"])
             return int(meta["step"])
-        self.state = init_state(self.model, jax.random.key(self.tcfg.seed), self.tcfg.train.opt)
+        # under jit, so the state is built in place: sharded over the mesh
+        # when there is one, never first whole on one device
+        kw = {} if self.mesh is None else {"out_shardings": self._state_shardings(template)}
+        self.state = jax.jit(init, **kw)(key)
         return 0
 
     def _handle_sigterm(self, signum, frame):
@@ -101,16 +109,15 @@ class Trainer:
             with mesh_context(self.mesh):
                 start = self._init_or_restore()
                 if self.mesh is not None:
-                    axes = state_axes(
-                        self.model, self.tcfg.train.opt,
-                        jax.eval_shape(lambda: self.state),
+                    st_sh = self._state_shardings(jax.eval_shape(lambda: self.state))
+                    jitted = jax.jit(
+                        step_fn, in_shardings=(st_sh, None), out_shardings=(st_sh, None),
+                        donate_argnums=0,
                     )
-                    sds = jax.eval_shape(lambda: self.state)
-                    st_sh = tree_shardings(self.mesh, sds, axes)
-                    jitted = jax.jit(step_fn, in_shardings=(st_sh, None), donate_argnums=0)
                 else:
                     jitted = jax.jit(step_fn, donate_argnums=0)
 
+                saved = False
                 for step in range(start, tcfg.steps):
                     t0 = time.monotonic()
                     batch = self.pipeline.next_batch()
@@ -129,7 +136,7 @@ class Trainer:
                             f"({dt*1e3:.0f} ms)",
                             flush=True,
                         )
-                    self.ckpt.maybe_save(
+                    saved = self.ckpt.maybe_save(
                         step + 1, self.state, {"pipeline": self.pipeline.state_dict()}
                     )
                     if self._preempted:
@@ -139,7 +146,10 @@ class Trainer:
                         self.ckpt.wait()
                         print(f"preempted at step {step+1}; checkpoint committed", flush=True)
                         return {"status": "preempted", "step": step + 1, "metrics": self.metrics_log}
-                self.ckpt.save_now(tcfg.steps, self.state, {"pipeline": self.pipeline.state_dict()})
+                if not saved:  # the interval has not just saved the last step
+                    self.ckpt.save_now(
+                        tcfg.steps, self.state, {"pipeline": self.pipeline.state_dict()}
+                    )
                 self.ckpt.wait()
             return {"status": "done", "step": tcfg.steps, "metrics": self.metrics_log}
         finally:

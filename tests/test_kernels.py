@@ -106,3 +106,39 @@ def test_rglru_carried_state():
     yr, hr = rglru_reference(x, r, i, lam)
     np.testing.assert_allclose(np.asarray(jnp.concatenate([y1, y2], 1)), np.asarray(yr), atol=1e-5)
     np.testing.assert_allclose(np.asarray(h2), np.asarray(hr), atol=1e-5)
+
+
+def test_ops_wrappers_run_the_kernels():
+    """The public wrappers pick interpret mode on the CPU backend (the
+    compiled kernel elsewhere) and agree with the oracles."""
+    from repro.kernels import ops
+
+    B, T, H, K, hd = 1, 128, 4, 2, 64
+    q = jnp.asarray(RNG.normal(size=(B, T, H, hd)), jnp.float32)
+    k = jnp.asarray(RNG.normal(size=(B, T, K, hd)), jnp.float32)
+    v = jnp.asarray(RNG.normal(size=(B, T, K, hd)), jnp.float32)
+    np.testing.assert_allclose(
+        np.asarray(ops.attention(q, k, v)), np.asarray(mha_reference(q, k, v)), atol=2e-5, rtol=1e-2
+    )
+    P, page, maxp = 8, 128, 2
+    qd = jnp.asarray(RNG.normal(size=(2, H, hd)), jnp.float32)
+    pk = jnp.asarray(RNG.normal(size=(P, page, K, hd)), jnp.float32)
+    pv = jnp.asarray(RNG.normal(size=(P, page, K, hd)), jnp.float32)
+    pt = jnp.asarray(RNG.integers(0, P, size=(2, maxp)), jnp.int32)
+    lengths = jnp.asarray([5, 200], jnp.int32)
+    np.testing.assert_allclose(
+        np.asarray(ops.paged_decode(qd, pk, pv, pt, lengths)),
+        np.asarray(paged_decode_reference(qd, pk, pv, pt, lengths)), atol=2e-5, rtol=1e-2,
+    )
+    x = jnp.asarray(RNG.normal(size=(1, 64, 2, 16)), jnp.float32)
+    dA = -jnp.abs(jnp.asarray(RNG.normal(size=(1, 64, 2)), jnp.float32)) * 0.3
+    Bs = jnp.asarray(RNG.normal(size=(1, 64, 1, 32)), jnp.float32)
+    Cs = jnp.asarray(RNG.normal(size=(1, 64, 1, 32)), jnp.float32)
+    for got, want in zip(ops.ssd_scan(x, dA, Bs, Cs, 32), ssd_chunk_reference(x, dA, Bs, Cs)):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=5e-4, rtol=1e-3)
+    xr = jnp.asarray(RNG.normal(size=(1, 64, 128)), jnp.float32)
+    r = jnp.asarray(RNG.uniform(size=(1, 64, 128)), jnp.float32)
+    i = jnp.asarray(RNG.uniform(size=(1, 64, 128)), jnp.float32)
+    lam = jnp.asarray(RNG.uniform(0.5, 4.0, size=(128,)), jnp.float32)
+    for got, want in zip(ops.rglru(xr, r, i, lam), rglru_reference(xr, r, i, lam)):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)

@@ -372,7 +372,7 @@ if HAVE_HYPOTHESIS:
         and the model; the invariant re-checks full visible state at the
         latest read point and at every live snapshot."""
 
-        @initialize(target=st.none())
+        @initialize()
         def setup(self):
             import tempfile
 

@@ -116,3 +116,17 @@ def test_engine_greedy_matches_manual_decode():
         logits, cache = model.decode_step(params, cache, jnp.asarray([[toks[-1]]], jnp.int32))
         toks.append(int(jnp.argmax(logits[0])))
     assert req.tokens == toks
+
+
+def test_bf16_init_is_the_eager_init_cast():
+    """One jitted program gives the same bf16 parameters as the eager
+    fp32 init followed by a cast."""
+    from repro.serving.engine import bf16_init
+
+    cfg = get_config("qwen3-4b").reduced()
+    model = build_model(cfg)
+    got = bf16_init(model)(jax.random.key(0))
+    want = jax.tree.map(lambda p: p.astype(jnp.bfloat16), model.init(jax.random.key(0)))
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert g.dtype == jnp.bfloat16
+        np.testing.assert_array_equal(np.asarray(g, np.float32), np.asarray(w, np.float32))

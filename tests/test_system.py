@@ -4,6 +4,7 @@ contracts used by the dry-run."""
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.configs import SHAPES, get_config
 from repro.configs.base import ShapeCell
@@ -121,3 +122,29 @@ def test_pipeline_host_sharding():
     b0, b1 = p0.next_batch(), p1.next_batch()
     assert b0["tokens"].shape == (4, 16)
     assert not np.array_equal(b0["tokens"], b1["tokens"])  # different shards
+
+
+@pytest.mark.parametrize("from_env", [True, False])
+def test_compile_cache_placement(from_env, tmp_path, monkeypatch):
+    """$JAX_COMPILATION_CACHE_DIR wins and the helper sets nothing;
+    without it the cache sits at a fixed path in the checkout."""
+    from pathlib import Path
+
+    from repro.launch import compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    if from_env:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    try:
+        got = compile_cache.use_compile_cache()
+        if from_env:
+            assert got == str(tmp_path)
+            assert jax.config.jax_compilation_cache_dir == before
+        else:
+            root = Path(__file__).resolve().parents[1]
+            assert got == str(root / ".jax_cache")
+            assert jax.config.jax_compilation_cache_dir == got
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

@@ -54,6 +54,7 @@ def test_exact_resume_matches_uninterrupted(tmp_path):
     t_half = Trainer(CFG, _tcfg(d2, steps=5, interval=5, async_=False))
     t_half.run()
     t_half.close()  # process "dies" here
+    assert t_half.ckpt.save_count == 1  # the interval's save of step 5 is the last one
     t_resume = Trainer(CFG, _tcfg(d2, steps=10, interval=100))
     res = t_resume.run()
     assert res["step"] == 10
@@ -105,3 +106,29 @@ def test_straggler_detection(tmp_path):
     tr.close()
     assert tr.straggler_events >= 1
     assert events
+
+
+def test_mesh_resume_matches_uninterrupted(tmp_path):
+    """The mesh path: state built under jit with the mesh's shardings,
+    steps with pinned in/out shardings, restore through
+    ``load_distributed`` — resumed params equal an uninterrupted run."""
+    from repro.launch.mesh import make_host_mesh
+
+    mesh = make_host_mesh((1, 1))
+    d1, d2 = str(tmp_path / "a"), str(tmp_path / "b")
+    t_full = Trainer(CFG, _tcfg(d1, steps=6, interval=100), mesh=mesh)
+    t_full.run()
+    full_params = jax.device_get(t_full.state["params"])
+    t_full.close()
+
+    t_half = Trainer(CFG, _tcfg(d2, steps=3, interval=3), mesh=mesh)
+    t_half.run()
+    t_half.close()
+    t_resume = Trainer(CFG, _tcfg(d2, steps=6, interval=100), mesh=mesh)
+    res = t_resume.run()
+    assert res["step"] == 6
+    leaf = jax.tree.leaves(t_resume.state["params"])[0]
+    assert leaf.sharding.mesh.shape == mesh.shape
+    resumed_params = jax.device_get(t_resume.state["params"])
+    t_resume.close()
+    assert _params_equal(full_params, resumed_params)
