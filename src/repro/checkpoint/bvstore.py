@@ -17,6 +17,12 @@ re-shards onto whatever mesh the restarted job has (elastic restart).
 
 Incremental mode skips tensors whose content hash matches the previous
 step's — LSM levels naturally hold the deltas and compaction consolidates.
+
+Spans (``EngineStats.span``, the ``ckpt.*`` names of ``SPAN_NAMES``) time a
+save and a restore from inside, each carrying the checkpoint's ``step``
+and, per tensor, its ``leaf`` path. They record into the engine's
+``EngineStats`` beside its own ``db.*`` spans; a store over a ``ShardedDB``
+keeps an ``EngineStats`` of its own for them.
 """
 from __future__ import annotations
 
@@ -29,6 +35,7 @@ import msgpack
 import numpy as np
 
 from repro.core import DB, DBConfig, KVStore
+from repro.core.stats import EngineStats
 
 CHUNK = 4 << 20  # 4 MiB value chunks (page-aligned batches downstream)
 
@@ -56,19 +63,24 @@ class BVCheckpointStore:
         or a ``ShardedDB``) — the store takes ownership (``close()``
         closes it) and ``path``/``num_queues``/``sync_values``/``env``
         are ignored. Default: a fresh single-engine ``DB`` at ``path``."""
-        if db is not None:
-            self.db = db
-            return
-        cfg = DBConfig.bvlsm(
-            wal_mode="sync",  # metadata commits are synchronous
-            value_threshold=4096,
-            num_bvalue_queues=num_queues,
-            memtable_size=4 << 20,
-            bvcache_bytes=16 << 20,
-        )
-        cfg.sync_flush_io = sync_values
-        cfg.env = env  # pluggable filesystem (fault-injection tests)
-        self.db = DB.open(path, cfg)
+        if db is None:
+            cfg = DBConfig.bvlsm(
+                wal_mode="sync",  # metadata commits are synchronous
+                value_threshold=4096,
+                num_bvalue_queues=num_queues,
+                memtable_size=4 << 20,
+                bvcache_bytes=16 << 20,
+            )
+            cfg.sync_flush_io = sync_values
+            cfg.env = env  # pluggable filesystem (fault-injection tests)
+            db = DB.open(path, cfg)
+        self.db = db
+        engine = getattr(db, "stats", None)
+        self._stats = engine if isinstance(engine, EngineStats) else EngineStats()
+
+    def span(self, name: str, **args):
+        """A ``ckpt.*`` span, recorded where ``stats()["spans"]`` reads it."""
+        return self._stats.span(name, **args)
 
     def _value_barrier(self) -> None:
         """Every async BValue write durable before a META record commits.
@@ -91,44 +103,50 @@ class BVCheckpointStore:
         """Returns {path: (content_hash, src_step)} for incremental chaining —
         src_step is where the chunks PHYSICALLY live (chains of reuse keep
         pointing at the original writer)."""
-        leaves = _leaf_paths(state)
-        manifest = []
-        hashes: dict[str, tuple] = {}
-        reused = 0
-        for path, leaf in leaves:
-            arr = np.asarray(jax.device_get(leaf))
-            buf = arr.tobytes()
-            h = content_hash(buf)
-            entry = {
-                "path": path,
-                "shape": list(arr.shape),
-                "dtype": str(arr.dtype),
-                "chunks": max(1, -(-len(buf) // CHUNK)),
-                "hash": h,
+        with self.span("ckpt.save", step=step):
+            leaves = _leaf_paths(state)
+            manifest = []
+            hashes: dict[str, tuple] = {}
+            reused = 0
+            for path, leaf in leaves:
+                with self.span("ckpt.serialize", step=step, leaf=path):
+                    arr = np.asarray(jax.device_get(leaf))
+                    buf = arr.tobytes()
+                with self.span("ckpt.hash", step=step, leaf=path):
+                    h = content_hash(buf)
+                entry = {
+                    "path": path,
+                    "shape": list(arr.shape),
+                    "dtype": str(arr.dtype),
+                    "chunks": max(1, -(-len(buf) // CHUNK)),
+                    "hash": h,
+                }
+                prev = prev_hashes.get(path) if prev_hashes else None
+                if prev is not None and prev[0] == h:
+                    entry["reuse_step"] = prev[1]  # original writer's step
+                    hashes[path] = (h, prev[1])
+                    reused += 1
+                else:
+                    with self.span("ckpt.put", step=step, leaf=path):
+                        for ci in range(entry["chunks"]):
+                            key = self._chunk_key(step, path, ci)
+                            self.db.put(key, buf[ci * CHUNK : (ci + 1) * CHUNK])
+                    hashes[path] = (h, step)
+                manifest.append(entry)
+            # barrier: every async BValue write durable before META commits
+            with self.span("ckpt.barrier", step=step):
+                self._value_barrier()
+            meta = {
+                "step": step,
+                "time": time.time(),
+                "manifest": manifest,
+                "extra": extra_meta or {},
+                "reused_tensors": reused,
             }
-            prev = prev_hashes.get(path) if prev_hashes else None
-            if prev is not None and prev[0] == h:
-                entry["reuse_step"] = prev[1]  # original writer's step
-                hashes[path] = (h, prev[1])
-                reused += 1
-            else:
-                for ci in range(entry["chunks"]):
-                    key = self._chunk_key(step, path, ci)
-                    self.db.put(key, buf[ci * CHUNK : (ci + 1) * CHUNK])
-                hashes[path] = (h, step)
-            manifest.append(entry)
-        # barrier: every async BValue write durable before META commits
-        self._value_barrier()
-        meta = {
-            "step": step,
-            "time": time.time(),
-            "manifest": manifest,
-            "extra": extra_meta or {},
-            "reused_tensors": reused,
-        }
-        self.db.put(self._meta_key(step), msgpack.packb(meta, use_bin_type=True))
-        self.db.flush()
-        return hashes
+            with self.span("ckpt.commit", step=step):
+                self.db.put(self._meta_key(step), msgpack.packb(meta, use_bin_type=True))
+                self.db.flush()
+            return hashes
 
     def _chunk_key(self, step: int, path: str, ci: int) -> bytes:
         return f"ckpt/{step:012d}/t{path}/c{ci:05d}".encode()
@@ -161,18 +179,21 @@ class BVCheckpointStore:
             step = self.latest_step()
             if step is None:
                 raise KeyError("no checkpoints")
-        meta = self.load_meta(step)
+        with self.span("ckpt.load_meta", step=step):
+            meta = self.load_meta(step)
         arrays: dict[str, np.ndarray] = {}
         for ent in meta["manifest"]:
-            src_step = ent.get("reuse_step", step)
-            parts = []
-            for ci in range(ent["chunks"]):
-                buf = self.db.get(self._chunk_key(src_step, ent["path"], ci))
-                if buf is None:
-                    raise IOError(f"missing chunk {ent['path']}#{ci} @ step {src_step}")
-                parts.append(buf)
-            raw = b"".join(parts)
-            arrays[ent["path"]] = np.frombuffer(raw, dtype=ent["dtype"]).reshape(ent["shape"])
+            path, src_step = ent["path"], ent.get("reuse_step", step)
+            with self.span("ckpt.read", step=step, leaf=path):
+                parts = []
+                for ci in range(ent["chunks"]):
+                    buf = self.db.get(self._chunk_key(src_step, path, ci))
+                    if buf is None:
+                        raise IOError(f"missing chunk {path}#{ci} @ step {src_step}")
+                    parts.append(buf)
+            with self.span("ckpt.join", step=step, leaf=path):
+                raw = b"".join(parts)
+                arrays[path] = np.frombuffer(raw, dtype=ent["dtype"]).reshape(ent["shape"])
         if template is None:
             return arrays, meta
         flat, treedef = jax.tree_util.tree_flatten_with_path(template)
@@ -187,7 +208,8 @@ class BVCheckpointStore:
         state, meta = self.load(step, template=template)
         sds = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), state)
         shardings = tree_shardings(mesh, sds, axes_tree)
-        out = jax.tree.map(lambda a, s: jax.device_put(a, s), state, shardings)
+        with self.span("ckpt.place", step=meta["step"]):
+            out = jax.tree.map(lambda a, s: jax.device_put(a, s), state, shardings)
         return out, meta
 
     # ------------------------------------------------------------------
@@ -220,7 +242,13 @@ class BVCheckpointStore:
         return directory
 
     def stats(self) -> dict:
-        return self.db.stats()
+        """The engine's ``stats()``; over a ``ShardedDB`` (each shard's
+        ``db.*`` spans under ``per_shard``) with this store's ``ckpt.*``
+        spans under ``spans``."""
+        out = self.db.stats()
+        if self._stats is not getattr(self.db, "stats", None):
+            out["spans"] = self._stats.spans()
+        return out
 
     def close(self) -> None:
         self.db.close()

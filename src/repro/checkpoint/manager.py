@@ -34,6 +34,7 @@ class CheckpointManager:
         self.incremental = incremental
         self._prev_hashes: dict | None = None
         self._pending: threading.Thread | None = None
+        self._pending_step: int | None = None
         self._lock = threading.Lock()
         self.save_count = 0
         self.stall_seconds = 0.0  # time the TRAIN LOOP was blocked
@@ -48,7 +49,8 @@ class CheckpointManager:
     def save_now(self, step: int, state, extra_meta: dict | None = None) -> None:
         t0 = time.monotonic()
         self.wait()  # one in-flight checkpoint at a time
-        host_state = jax.tree.map(lambda x: jax.device_get(x), state)
+        with self.store.span("ckpt.snapshot", step=step):
+            host_state = jax.tree.map(lambda x: jax.device_get(x), state)
         snapshot_s = time.monotonic() - t0
 
         def _write():
@@ -63,6 +65,7 @@ class CheckpointManager:
 
         if self.async_save:
             self._pending = threading.Thread(target=_write, name=f"ckpt-{step}", daemon=True)
+            self._pending_step = step
             self._pending.start()
             self.stall_seconds += snapshot_s  # loop only pays the snapshot
         else:
@@ -97,7 +100,8 @@ class CheckpointManager:
     def wait(self) -> None:
         if self._pending is not None and self._pending.is_alive():
             t0 = time.monotonic()
-            self._pending.join()
+            with self.store.span("ckpt.wait", step=self._pending_step):
+                self._pending.join()
             self.stall_seconds += time.monotonic() - t0
         self._pending = None
 

@@ -9,11 +9,11 @@ Key-ValueOffset record is appended to the WAL.
 
 Write modes:
 
-* ``put_sync``  — caller pwrites at its reserved offset and fsyncs before
+* sync — caller pwrites at its reserved offset and fsyncs before
   returning (WAL-enabled strong-consistency path: value durable before the
   WAL record that references it). Concurrent callers on different queues
   proceed in parallel (pwrite/fsync release the GIL).
-* ``put_async`` — reservation returns immediately; the queue's writer thread
+* async — reservation returns immediately; the queue's writer thread
   batches contiguous runs to page multiples, pwrites, fsyncs, then unpins
   the corresponding BVCache entries (which held the only copy meanwhile).
 
@@ -50,6 +50,7 @@ from dataclasses import dataclass
 from .env import DEFAULT_ENV
 from .errors import CorruptionError
 from .record import ValueOffset
+from .stats import no_span
 
 _SENTINEL = object()
 
@@ -133,13 +134,6 @@ class _BValueQueue:
             self.mgr.env.close_fd(close_fd)
 
     # -- sync path ------------------------------------------------------
-    def write_sync(self, file_id: int, offset: int, value: bytes) -> None:
-        fd = self._fd_for(file_id)
-        self.mgr.env.pwrite(fd, value, offset)
-        self.mgr.env.fsync(fd)
-        self.mgr._account(len(value), fsyncs=1)
-        self._release(file_id)
-
     def _persist_resvs(self, resvs: list[tuple[int, int, bytes]]) -> int:
         """Shared sync/async persistence: coalesce in-order reservations
         [(file_id, offset, value)] into contiguous pwrite runs, fsync each
@@ -160,10 +154,12 @@ class _BValueQueue:
             if fd is None:
                 fd = touched[fid] = self._fd_for(fid)
             blob = b"".join(v for _, _, v in run)
-            self.mgr.env.pwrite(fd, blob, run[0][1])
+            with self.mgr.span("bvalue.pwrite"):
+                self.mgr.env.pwrite(fd, blob, run[0][1])
             total += len(blob)
         for fd in touched.values():
-            self.mgr.env.fsync(fd)
+            with self.mgr.span("bvalue.fsync"):
+                self.mgr.env.fsync(fd)
         self.mgr._account(total, fsyncs=len(touched))
         for fid, _, _ in resvs:
             self._release(fid)
@@ -222,7 +218,8 @@ class _BValueQueue:
     def _flush_batch(self, batch: list[_Pending]) -> None:
         if not batch:
             return
-        total = self._persist_resvs([(p.file_id, p.offset, p.value) for p in batch])
+        with self.mgr.span("bvalue.write"):
+            total = self._persist_resvs([(p.file_id, p.offset, p.value) for p in batch])
         # unpin callbacks BEFORE signalling the drain barrier: wait_drained()
         # returning must mean the batch is persisted AND its cache entries
         # are unpinned.
@@ -291,6 +288,7 @@ class BValueManager:
         self.max_file_bytes = max_file_bytes
         self.gather_window_s = gather_window_s
         self.stats = stats
+        self.span = stats.span if stats is not None else no_span
         # unified device budget: charge the shared token bucket at dispatch
         # time, at the priority the calling context reports (None = no
         # charging — the pre-unification background-only model)
@@ -336,15 +334,7 @@ class BValueManager:
                 self.limiter.request(nbytes, pri)
 
     def put(self, key: bytes, value: bytes, sync: bool) -> ValueOffset:
-        self._charge(len(value))
-        q = self._pick_queue()
-        file_id, off = q.reserve(len(value))
-        voff = ValueOffset(file_id, off, len(value), zlib.crc32(value) & 0xFFFFFFFF)
-        if sync or not self.async_writes:
-            q.write_sync(file_id, off, value)
-        else:
-            q.submit(_Pending(file_id, off, value, key))
-        return voff
+        return self.put_many([(key, value)], sync)[0]
 
     def put_many(
         self, items: list[tuple[bytes, bytes]], sync: bool, on_reserved=None
@@ -357,32 +347,38 @@ class BValueManager:
         ``on_reserved(key, voff, value)`` fires per item BEFORE anything is
         handed to a writer thread — the DB uses it to insert pinned BVCache
         entries so the persist-completion unpin can never race ahead of the
-        insert."""
+        insert.
+
+        A durable call is one ``bvalue.write`` span (crc32, pwrite and fsync
+        on the caller's thread); an async batch is timed where its queue's
+        thread persists it."""
         self._charge(sum(len(v) for _, v in items))
-        voffs: list[ValueOffset] = []
-        per_q: dict[int, list[tuple[int, int, bytes, bytes]]] = {}
-        for key, value in items:
-            q = self._pick_queue()
-            file_id, off = q.reserve(len(value))
-            voff = ValueOffset(file_id, off, len(value), zlib.crc32(value) & 0xFFFFFFFF)
-            voffs.append(voff)
-            if on_reserved is not None:
-                on_reserved(key, voff, value)
-            per_q.setdefault(q.qid, []).append((file_id, off, value, key))
         durable = sync or not self.async_writes
-        for qid, resvs in per_q.items():
-            q = self.queues[qid]
-            if durable:
-                q.write_sync_many([(fid, off, val) for fid, off, val, _ in resvs])
-            else:
-                for fid, off, val, key in resvs:
-                    q.submit(_Pending(fid, off, val, key))
+        with self.span("bvalue.write") if durable else no_span("bvalue.write"):
+            voffs: list[ValueOffset] = []
+            per_q: dict[int, list[tuple[int, int, bytes, bytes]]] = {}
+            for key, value in items:
+                q = self._pick_queue()
+                file_id, off = q.reserve(len(value))
+                voff = ValueOffset(file_id, off, len(value), zlib.crc32(value) & 0xFFFFFFFF)
+                voffs.append(voff)
+                if on_reserved is not None:
+                    on_reserved(key, voff, value)
+                per_q.setdefault(q.qid, []).append((file_id, off, value, key))
+            for qid, resvs in per_q.items():
+                q = self.queues[qid]
+                if durable:
+                    q.write_sync_many([(fid, off, val) for fid, off, val, _ in resvs])
+                else:
+                    for fid, off, val, key in resvs:
+                        q.submit(_Pending(fid, off, val, key))
         return voffs
 
     # -- read path ------------------------------------------------------------
     def get(self, voff: ValueOffset, verify: bool = False) -> bytes:
         fd = self._reader_fd(voff.file_id)
-        buf = self.env.pread(fd, voff.size, voff.offset)
+        with self.span("bvalue.pread"):
+            buf = self.env.pread(fd, voff.size, voff.offset)
         if len(buf) != voff.size:
             # short read ≠ corruption: it's a truncation/roll race and is
             # retryable (plain IOError, classified transient)

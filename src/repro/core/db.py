@@ -698,8 +698,16 @@ class DB:
         """Store ``key -> value``. Values >= ``value_threshold`` (in ``wal``
         separation mode) are streamed to the BValue store first; only a
         ValueOffset rides the WAL/MemTable. Durable on return under sync
-        WAL. Thread-safe: concurrent puts merge into commit groups."""
-        self._commit([(kTypeValue, key, value)])
+        WAL. Thread-safe: concurrent puts merge into commit groups.
+
+        A put of a big value (``value_threshold`` bytes or more) is one
+        ``db.put`` span; a small one records none, as the span would add
+        a tenth to its cost."""
+        if len(value) < self.cfg.value_threshold:
+            self._commit([(kTypeValue, key, value)])
+            return
+        with self.stats.span("db.put"):
+            self._commit([(kTypeValue, key, value)])
 
     def delete(self, key: bytes) -> None:
         """Write a tombstone for ``key`` (the value, if separated, is

@@ -44,6 +44,7 @@ from .errors import JOB_ABORTED, BackgroundError
 # both domains (thread pool and I/O budget), compaction/GC LOW in both —
 # one source of truth keeps the two domains from desynchronizing
 from .ratelimiter import PRI_HIGH, PRI_LOW  # noqa: F401  (re-exported)
+from .stats import no_span
 
 
 class Job:
@@ -72,7 +73,8 @@ class JobScheduler:
         self._discard = False
         self.error: BaseException | None = None
         self.on_job_done = None
-        self._stats = stats
+        # each job runs in an ``engine.<kind>`` span: the ``jobs`` table
+        self._span = stats.span if stats is not None else no_span
         self._threads: list[threading.Thread] = []
         for i in range(max(1, flush_threads)):
             t = threading.Thread(
@@ -141,17 +143,15 @@ class JobScheduler:
                     if self._stop:
                         return
                     self._cv.wait()
-            t0 = time.monotonic()
             try:
-                job.fn()
+                with self._span(f"engine.{job.kind}"):
+                    job.fn()
             except BaseException as e:  # surface instead of dying silently
                 with self._cv:
                     if self.error is None:
                         self.error = e
                 traceback.print_exc()
             finally:
-                if self._stats is not None:
-                    self._stats.record_job(job.kind, time.monotonic() - t0)
                 hook = self.on_job_done
                 if hook is not None:
                     try:

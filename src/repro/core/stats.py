@@ -1,6 +1,5 @@
 """Engine statistics: byte counters per I/O class (wal / flush / compaction /
-bvalue), stall accounting, and a throughput timeline recorder used to
-reproduce the paper's Fig. 2 / Fig. 9 instant-vs-average plots.
+bvalue), stall accounting, and a table of timed spans.
 
 ``write_amp`` = total device bytes / user payload bytes — the paper's core
 metric.
@@ -18,13 +17,119 @@ histograms the number of commit groups in flight at group-formation time
 ``wal_group_effective_bytes`` (current latency-targeted byte cap) and
 ``wal_persist_ewma_s`` (smoothed group persist latency). ``wal_fsync_skips``
 counts groups whose durability was covered by a later-started fsync.
+
+Spans (``EngineStats.span``): named, timed regions of the store, its
+background jobs and the checkpoint layer above it, all named in
+``SPAN_NAMES``. Each name's row holds the count, total, self and longest
+seconds and a reservoir of durations; ``snapshot()["spans"]`` exports it.
+While a ``jax.profiler`` trace is being taken (and only if the process has
+imported JAX already: this package never imports it) a span also enters a
+``jax.profiler.TraceAnnotation``, so it lands on its thread's line of the
+trace's host plane, on the device ops' clock.
 """
 from __future__ import annotations
 
+import contextlib
 import random
+import sys
 import threading
 import time
 from collections import defaultdict
+
+# Every span the program records, by layer; parents before their children.
+SPAN_NAMES = (
+    # checkpoint manager, on the train loop's thread
+    "ckpt.wait", "ckpt.snapshot",
+    # checkpoint store, on the save thread: per leaf serialize/hash/put
+    "ckpt.save", "ckpt.serialize", "ckpt.hash", "ckpt.put",
+    "ckpt.barrier", "ckpt.commit",
+    # restore, on the loop's thread: per leaf read/join, then placement
+    "ckpt.restore", "ckpt.load_meta", "ckpt.read", "ckpt.join", "ckpt.place",
+    # store engine: the big-value path only (a span costs a few µs, a
+    # tenth of a small put or a cached get)
+    "db.put", "wal.fsync",
+    "bvalue.write", "bvalue.pwrite", "bvalue.fsync",
+    "bvalue.pread",
+    # background jobs, one per JobScheduler job kind
+    "engine.flush", "engine.compaction", "engine.gc", "engine.repl_apply", "engine.scrub",
+)
+_SPAN_SET = frozenset(SPAN_NAMES)
+_JOB_PREFIX = "engine."
+_RESERVOIR = 10_000  # samples kept per stream (stall events, each span name)
+_NULL = contextlib.nullcontext()
+# the open spans of each thread, innermost last, whichever EngineStats they
+# record into: a span's self time excludes every child on its thread
+_open = threading.local()
+_clock = time.perf_counter
+
+
+def no_span(name: str, **args) -> contextlib.nullcontext:
+    """``EngineStats.span``'s stand-in for a component built without stats."""
+    return _NULL
+
+
+def _keep_sample(samples: list[float], seen: int, x: float) -> None:
+    """Reservoir sampling: after ``seen`` values (``x`` included), every
+    one of them is in ``samples`` with the same probability."""
+    if len(samples) < _RESERVOIR:
+        samples.append(x)
+    else:
+        j = int(random.random() * seen)  # randrange(seen), at a fifth the cost
+        if j < _RESERVOIR:
+            samples[j] = x
+
+
+def _quantile(samples: list[float], q: float) -> float:
+    if not samples:
+        return 0.0
+    s = sorted(samples)
+    return s[min(len(s) - 1, int(len(s) * q))]
+
+
+class _SpanRow:
+    __slots__ = ("count", "seconds", "self_seconds", "max_seconds", "samples")
+
+    def __init__(self) -> None:
+        self.count = 0
+        self.seconds = 0.0
+        self.self_seconds = 0.0
+        self.max_seconds = 0.0
+        self.samples: list[float] = []
+
+
+class _Span:
+    """One timed region; see ``EngineStats.span``."""
+
+    __slots__ = ("stats", "name", "args", "t0", "child_s", "annotation")
+
+    def __init__(self, stats: "EngineStats", name: str, args: dict) -> None:
+        if name not in _SPAN_SET:
+            raise ValueError(f"span {name!r} is not in SPAN_NAMES")
+        self.stats, self.name, self.args = stats, name, args
+        self.child_s = 0.0
+        self.annotation = None
+
+    def __enter__(self) -> "_Span":
+        profiler = sys.modules.get("jax.profiler")
+        if profiler is not None and profiler.TraceAnnotation.is_enabled():
+            self.annotation = profiler.TraceAnnotation(self.name, **self.args)
+            self.annotation.__enter__()
+        try:
+            _open.stack.append(self)
+        except AttributeError:
+            _open.stack = [self]
+        self.t0 = _clock()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        dur = _clock() - self.t0
+        stack = _open.stack
+        stack.pop()
+        if stack:
+            stack[-1].child_s += dur
+        if self.annotation is not None:
+            self.annotation.__exit__(*exc)
+        self.stats._record_span(self.name, dur, dur - self.child_s)
 
 
 class EngineStats:
@@ -45,9 +150,10 @@ class EngineStats:
     * ``gc_slices`` — auto-GC passes that yielded early on the slice budget
     * ``group_commits`` / ``group_writers`` / ``group_entries`` — group
       commit totals; ``memtable_shard_applies`` — groups applied sharded
-    * ``job_{flush,compaction,gc}_count`` (+ the ``jobs`` table with wall
-      seconds per kind) — background scheduler jobs; ``subcompactions`` —
-      key-range shards fanned out by partitioned compactions
+    * ``job_{flush,compaction,gc,...}_count`` (+ the ``jobs`` table with
+      wall seconds per kind; both read the ``engine.<kind>`` spans) —
+      background scheduler jobs; ``subcompactions`` — key-range shards
+      fanned out by partitioned compactions
     * ``rate_limiter_waits`` / ``rate_limiter_wait_seconds`` — background
       I/O token-bucket backpressure; ``rate_limiter_fg_bytes`` — foreground
       value-log bytes charged to the unified budget (accounted, never
@@ -74,8 +180,9 @@ class EngineStats:
     ``pipeline_depth_max``. Structures: ``group_size_hist`` (pow2 bucket →
     count), ``pipeline_depth_hist`` (depth → count), ``gauges`` (last-value,
     e.g. ``wal_group_effective_bytes`` / ``wal_persist_ewma_s``),
-    ``timeline`` (t, acked bytes) feeding ``interval_throughput``, and
-    stall accounting (``stall_seconds`` / ``stall_events``).
+    stall accounting (``stall_seconds`` / ``stall_events``), and ``spans``
+    (name → ``count``, ``seconds``, ``self_seconds`` (without the child
+    spans on its thread), ``max_seconds``, ``p50_ms``, ``p99_ms``).
     """
 
     def __init__(self) -> None:
@@ -83,13 +190,11 @@ class EngineStats:
         self.counters: dict[str, int] = defaultdict(int)
         self.stall_seconds = 0.0
         self.stall_events = 0
-        self._t0 = time.monotonic()
-        self.timeline: list[tuple[float, int]] = []  # (t, user_bytes_acked)
         self.group_size_hist: dict[int, int] = defaultdict(int)  # pow2 bucket -> count
         self.pipeline_depth_hist: dict[int, int] = defaultdict(int)  # depth -> count
         self.stall_hist: dict[int, int] = defaultdict(int)  # pow2 ms bucket -> count
         self._stall_samples: list[float] = []  # capped reservoir for p99
-        self.job_seconds: dict[str, float] = defaultdict(float)  # kind -> wall s
+        self._spans: dict[str, _SpanRow] = {}
         self.gauges: dict[str, float] = {}  # last-value gauges (adaptive caps, ...)
         self._block_cache = None  # BlockCache; its counters merge into snapshot()
 
@@ -114,39 +219,51 @@ class EngineStats:
             self.counters[f"stall_{kind}_seconds"] += seconds
             ms = seconds * 1e3
             self.stall_hist[1 << max(0, int(ms).bit_length())] += 1
-            # true reservoir sample: every event over the run has equal
-            # probability of being retained, so stall_p99_ms reflects the
-            # whole run, not just its first 10k events
-            if len(self._stall_samples) < 10_000:
-                self._stall_samples.append(seconds)
-            else:
-                j = random.randrange(self.stall_events)
-                if j < 10_000:
-                    self._stall_samples[j] = seconds
+            # a true reservoir: stall_p99_ms reflects the whole run, not
+            # just its first 10k events
+            _keep_sample(self._stall_samples, self.stall_events, seconds)
 
-    def record_job(self, kind: str, seconds: float) -> None:
-        """Completion of one background job (flush/compaction/gc): counts
-        and total wall seconds per kind feed the ``jobs`` snapshot table."""
+    def span(self, name: str, **args) -> _Span:
+        """Context manager timing one region named in ``SPAN_NAMES``; ``args``
+        (e.g. ``step=``, ``leaf=``) go to the profiler's annotation only."""
+        return _Span(self, name, args)
+
+    def _record_span(self, name: str, seconds: float, self_seconds: float) -> None:
         with self._lock:
-            self.counters[f"job_{kind}_count"] += 1
-            self.job_seconds[kind] += seconds
+            row = self._spans.get(name)
+            if row is None:
+                row = self._spans[name] = _SpanRow()
+            row.count += 1
+            row.seconds += seconds
+            row.self_seconds += self_seconds
+            if seconds > row.max_seconds:
+                row.max_seconds = seconds
+            _keep_sample(row.samples, row.count, seconds)
+
+    def spans(self) -> dict[str, dict]:
+        """The span table, name → its totals and duration quantiles."""
+        with self._lock:
+            rows = [(n, r.count, r.seconds, r.self_seconds, r.max_seconds, list(r.samples))
+                    for n, r in self._spans.items()]
+        return {
+            n: {"count": c, "seconds": s, "self_seconds": own, "max_seconds": mx,
+                "p50_ms": _quantile(smp, 0.5) * 1e3, "p99_ms": _quantile(smp, 0.99) * 1e3}
+            for n, c, s, own, mx, smp in sorted(rows)
+        }
 
     def stall_p99_ms(self) -> float:
         with self._lock:
-            samples = sorted(self._stall_samples)
-        if not samples:
-            return 0.0
-        return samples[min(len(samples) - 1, int(len(samples) * 0.99))] * 1e3
+            samples = list(self._stall_samples)
+        return _quantile(samples, 0.99) * 1e3
 
     def mark_user_write(self, nbytes: int) -> None:
         self.mark_user_writes(1, nbytes)
 
     def mark_user_writes(self, count: int, nbytes: int) -> None:
-        """Bulk ack: one lock acquisition + one timeline point per group."""
+        """Bulk ack: one lock acquisition per group."""
         with self._lock:
             self.counters["user_writes"] += count
             self.counters["user_bytes"] += nbytes
-            self.timeline.append((time.monotonic() - self._t0, self.counters["user_bytes"]))
 
     def record_group(self, n_writers: int, n_entries: int) -> None:
         """One group commit: n_writers batches merged into one WAL write."""
@@ -203,38 +320,20 @@ class EngineStats:
             return 0.0
         return self._block_cache.stats()["block_cache_hit_rate"]
 
-    def interval_throughput(self, interval_s: float = 10.0) -> list[tuple[float, float]]:
-        """(t_end, MB/s) per interval — the paper's 10-second instant curve."""
-        out = []
-        if not self.timeline:
-            return out
-        t_end = interval_s
-        prev_bytes = 0
-        i = 0
-        last_t = self.timeline[-1][0]
-        while t_end <= last_t + interval_s:
-            while i < len(self.timeline) and self.timeline[i][0] <= t_end:
-                i += 1
-            cur = self.timeline[i - 1][1] if i > 0 else 0
-            out.append((t_end, (cur - prev_bytes) / interval_s / 1e6))
-            prev_bytes = cur
-            t_end += interval_s
-        return out
-
     def snapshot(self) -> dict:
         with self._lock:
             d = dict(self.counters)
             hist = dict(sorted(self.group_size_hist.items()))
             depth_hist = dict(sorted(self.pipeline_depth_hist.items()))
             stall_hist = dict(sorted(self.stall_hist.items()))
-            jobs = {
-                kind: {
-                    "count": self.counters.get(f"job_{kind}_count", 0),
-                    "seconds": secs,
-                }
-                for kind, secs in sorted(self.job_seconds.items())
-            }
             gauges = dict(self.gauges)
+        spans = self.spans()
+        jobs = {
+            name[len(_JOB_PREFIX):]: {"count": row["count"], "seconds": row["seconds"]}
+            for name, row in spans.items() if name.startswith(_JOB_PREFIX)
+        }
+        for kind, job in jobs.items():
+            d[f"job_{kind}_count"] = job["count"]
         for k in (
             "wal_bytes",
             "flush_bytes",
@@ -260,6 +359,7 @@ class EngineStats:
         d["stall_hist"] = stall_hist
         d["stall_p99_ms"] = self.stall_p99_ms()
         d["jobs"] = jobs
+        d["spans"] = spans
         d.setdefault("rate_limiter_waits", 0)
         d.setdefault("rate_limiter_wait_seconds", 0.0)
         d.setdefault("rate_limiter_fg_bytes", 0)

@@ -39,6 +39,7 @@ import threading
 import os
 
 from .env import DEFAULT_ENV
+from .stats import no_span
 from .record import (
     decode_varint,
     frame_records,
@@ -63,6 +64,7 @@ class WALWriter:
         self._env = env or DEFAULT_ENV
         self._f = self._env.open(path, "ab", buffering=0)
         self._stats = stats
+        self._span = stats.span if stats is not None else no_span
         self._closed = False
         # ticket barrier state (sync + async: file/buffer order must match
         # sequence order for hole-free replay)
@@ -155,7 +157,8 @@ class WALWriter:
         if self.mode == "async":
             self._drain()
         else:
-            self._env.fsync(self._f)
+            with self._span("wal.fsync"):
+                self._env.fsync(self._f)
 
     def close(self, drop_buffered: bool = False) -> None:
         """drop_buffered=True simulates a crash with unflushed async buffer."""
@@ -217,7 +220,8 @@ class WALWriter:
                     break
                 self._order_cv.wait()
         try:
-            self._env.fsync(self._f)
+            with self._span("wal.fsync"):
+                self._env.fsync(self._f)
         finally:
             with self._order_cv:
                 self._sync_in_flight = False
@@ -252,7 +256,8 @@ class WALWriter:
         if buf:
             blob = b"".join(buf)
             self._f.write(blob)
-            self._env.fsync(self._f)
+            with self._span("wal.fsync"):
+                self._env.fsync(self._f)
             if self._stats:
                 self._stats.add("wal_bytes", len(blob))
                 self._stats.add("wal_fsyncs")

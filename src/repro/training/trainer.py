@@ -83,13 +83,15 @@ class Trainer:
         key = jax.random.key(self.tcfg.seed)
         template = jax.eval_shape(init, key)
         if latest is not None:
-            if self.mesh is not None:
-                axes = state_axes(self.model, self.tcfg.train.opt, template)
-                self.state, meta = self.store.load_distributed(self.mesh, template, axes, latest)
-            else:
-                self.state, meta = self.store.load(latest, template=template)
-                self.state = jax.tree.map(jax.numpy.asarray, self.state)
-            self.pipeline.load_state_dict(meta["extra"]["pipeline"])
+            with self.store.span("ckpt.restore", step=latest):
+                if self.mesh is not None:
+                    axes = state_axes(self.model, self.tcfg.train.opt, template)
+                    self.state, meta = self.store.load_distributed(self.mesh, template, axes, latest)
+                else:
+                    self.state, meta = self.store.load(latest, template=template)
+                    with self.store.span("ckpt.place", step=latest):  # dispatch; no wait
+                        self.state = jax.tree.map(jax.numpy.asarray, self.state)
+                self.pipeline.load_state_dict(meta["extra"]["pipeline"])
             return int(meta["step"])
         # under jit, so the state is built in place: sharded over the mesh
         # when there is one, never first whole on one device
