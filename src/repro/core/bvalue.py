@@ -153,7 +153,9 @@ class _BValueQueue:
             fd = touched.get(fid)
             if fd is None:
                 fd = touched[fid] = self._fd_for(fid)
-            blob = b"".join(v for _, _, v in run)
+            # a run of one value goes out as it is (a caller's memoryview
+            # uncopied); a longer run is joined into one pwrite
+            blob = run[0][2] if len(run) == 1 else b"".join(v for _, _, v in run)
             with self.mgr.span("bvalue.pwrite"):
                 self.mgr.env.pwrite(fd, blob, run[0][1])
             total += len(blob)
@@ -343,6 +345,9 @@ class BValueManager:
         values across all queues, then persist each queue's share with one
         fsync (sync mode) or one submission run (async mode). Returns the
         ValueOffsets in input order.
+
+        A value is ``bytes`` or, on the durable path, a flat ``memoryview``
+        that is read only until this call returns (``DB._commit``).
 
         ``on_reserved(key, voff, value)`` fires per item BEFORE anything is
         handed to a writer thread — the DB uses it to insert pinned BVCache

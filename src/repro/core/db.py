@@ -102,6 +102,22 @@ from .wal import WALWriter
 from .writebatch import WriteBatch
 
 
+def _byte_view(value) -> bytes | memoryview:
+    """A put's value as the engine takes it: ``bytes`` as they are, any
+    other buffer as a flat ``memoryview`` of its bytes, so ``len()``
+    counts bytes. Anything but a C-contiguous buffer of one-byte items is
+    refused with ``TypeError``."""
+    if isinstance(value, bytes):
+        return value
+    mv = memoryview(value)  # TypeError for an object that is no buffer
+    if mv.itemsize != 1 or not mv.c_contiguous:
+        raise TypeError(
+            "a value must be bytes or a C-contiguous buffer of one-byte items, "
+            f"not {type(value).__name__} of format {mv.format!r}"
+        )
+    return mv if mv.ndim == 1 and mv.format == "B" else mv.cast("B")
+
+
 class _Writer:
     """One queued commit: a batch's memtable-ready entries + ack state.
 
@@ -700,9 +716,15 @@ class DB:
         ValueOffset rides the WAL/MemTable. Durable on return under sync
         WAL. Thread-safe: concurrent puts merge into commit groups.
 
+        ``value`` is ``bytes`` or a C-contiguous byte buffer, such as a
+        ``memoryview`` of a ``uint8`` array; the engine keeps no reference
+        to a buffer that is not ``bytes`` once ``put`` returns (see
+        ``_commit``).
+
         A put of a big value (``value_threshold`` bytes or more) is one
         ``db.put`` span; a small one records none, as the span would add
         a tenth to its cost."""
+        value = _byte_view(value)
         if len(value) < self.cfg.value_threshold:
             self._commit([(kTypeValue, key, value)])
             return
@@ -735,7 +757,15 @@ class DB:
         self, ops: list[tuple[int, bytes, bytes]], precondition=None
     ) -> bool:
         """Commit one batch; returns False iff a ``precondition`` made the
-        leader skip it (see :class:`_Writer`)."""
+        leader skip it (see :class:`_Writer`).
+
+        A value that is not ``bytes`` (a byte buffer, see ``put``) is
+        written from the caller's buffer only where the write is over
+        when the call returns: a big value under the sync WAL, pwritten
+        and fsynced in phase 1 and left out of the BVCache, so a later
+        ``get`` preads it. Every other such value, which the memtable or
+        an async BValue writer would hold past the call, is copied to
+        ``bytes`` here, once."""
         cfg = self.cfg
         # fail fast while read-only: don't separate values (phase 1 would
         # write them to the BValue log) for a commit that cannot proceed
@@ -746,6 +776,7 @@ class DB:
         # ALL queues in one put_many call before the leader commits. ---
         user_bytes = 0
         big_idx: list[int] = []
+        sync_value = cfg.wal_mode == "sync"
         for i, (type_, key, value) in enumerate(ops):
             if type_ == kTypeRangeDeletion:
                 # gate at write time, not flush time: a v<3 table cannot
@@ -757,15 +788,20 @@ class DB:
                     )
                 if not key < value:  # key=start, value=end (exclusive)
                     raise ValueError("delete_range: start must sort before end")
-            user_bytes += len(key) + len(value)
-            if (
+            value = _byte_view(value)
+            big = (
                 type_ == kTypeValue
                 and cfg.separation_mode == "wal"
                 and len(value) >= cfg.value_threshold
-            ):
+            )
+            if not isinstance(value, bytes):
+                if not (big and sync_value):
+                    value = bytes(value)  # held past the call: copy it once
+                ops[i] = (type_, key, value)
+            user_bytes += len(key) + len(value)
+            if big:
                 big_idx.append(i)
         if big_idx:
-            sync_value = cfg.wal_mode == "sync"
             on_reserved = None
             if not sync_value:
                 # async path: the pinned insert must land BEFORE the value is
@@ -781,7 +817,7 @@ class DB:
             )
             for i, voff in zip(big_idx, voffs):
                 _, key, value = ops[i]
-                if sync_value:
+                if sync_value and isinstance(value, bytes):
                     self.bvcache.insert(key, voff, value, pinned=False)
                 self.dead_tracker.on_write(voff)
                 ops[i] = (kTypeValuePtr, key, voff.encode())

@@ -165,6 +165,9 @@ class EngineStats:
       calls clearing the latch
     * ``corruptions_detected`` / ``files_quarantined`` — CRC-verified reads
       that failed and the files quarantined for it
+    * ``ckpt_view_bytes`` / ``ckpt_copy_bytes`` — checkpoint leaves' bytes
+      a ``BVCheckpointStore.save`` hashed and put from a view of the host
+      array / from the one contiguous copy a non-contiguous leaf takes
     * ``stall_stop_seconds`` / ``stall_delay_seconds`` — hard stops vs
       delayed-write-controller delays; ``stall_hist`` (pow2 ms bucket →
       count) and ``stall_p99_ms`` — the stall tail

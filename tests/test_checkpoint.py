@@ -6,10 +6,12 @@ import os
 
 import jax
 import jax.numpy as jnp
+import ml_dtypes
+import msgpack
 import numpy as np
 import pytest
 
-from repro.checkpoint.bvstore import BVCheckpointStore
+from repro.checkpoint.bvstore import CHUNK, BVCheckpointStore, _leaf_paths, content_hash
 from repro.checkpoint.manager import CheckpointManager
 
 
@@ -34,6 +36,92 @@ def test_save_load_roundtrip(tmp_path):
         assert meta["step"] == 10
         assert meta["extra"]["pipeline"]["step"] == 10
         jax.tree.map(lambda a, b: np.testing.assert_array_equal(np.asarray(a), np.asarray(b)), st, out)
+    finally:
+        store.close()
+
+
+def _leaf(case: str) -> np.ndarray:
+    """A leaf of more than one chunk, in each form a save has to take."""
+    a = np.arange(CHUNK // 4 + 3000, dtype=np.float32)
+    if case == "float32":
+        return a.reshape(-1, 8)
+    if case == "bfloat16":
+        return (a / 7).astype(ml_dtypes.bfloat16).reshape(8, -1)
+    return a.reshape(-1, 8).T  # a transposed view: not C-contiguous
+
+
+@pytest.mark.parametrize("case", ["float32", "bfloat16", "transposed"])
+def test_save_takes_leaf_bytes_exactly(tmp_path, case):
+    """The save hashes and puts a view of each host array: the load is byte
+    exact, each manifest hash is ``content_hash`` of ``tobytes()`` (the
+    format checkpoints written before had), and only a non-contiguous leaf
+    takes a copy."""
+    leaf = _leaf(case)
+    st = {"leaf": leaf, "w": np.arange(5000, dtype=np.float32), "step": np.int32(3)}
+    store = BVCheckpointStore(str(tmp_path / "ck"))
+    try:
+        store.save(1, st)
+        out, meta = store.load(1, template=st)
+        for path, want in _leaf_paths(st):
+            want = np.asarray(want)
+            got = dict(_leaf_paths(out))[path]
+            assert got.dtype == want.dtype and got.shape == want.shape, path
+            assert got.tobytes() == want.tobytes(), path
+        for ent, (path, want) in zip(meta["manifest"], _leaf_paths(st)):
+            raw = np.asarray(want).tobytes()
+            assert ent["path"] == path
+            assert ent["hash"] == content_hash(raw)
+            assert ent["chunks"] == max(1, -(-len(raw) // CHUNK))
+        total = sum(np.asarray(x).nbytes for x in jax.tree.leaves(st))
+        copied = leaf.nbytes if case == "transposed" else 0
+        stats = store.stats()
+        assert stats["ckpt_copy_bytes"] == copied
+        assert stats["ckpt_view_bytes"] == total - copied
+    finally:
+        store.close()
+
+
+def _save_from_tobytes(store: BVCheckpointStore, step: int, state) -> dict:
+    """A checkpoint as ``save`` wrote one when it serialized each leaf with
+    ``tobytes()`` and put ``bytes`` slices of it."""
+    manifest, hashes = [], {}
+    for path, leaf in _leaf_paths(state):
+        arr = np.asarray(leaf)
+        buf = arr.tobytes()
+        h = content_hash(buf)
+        n = max(1, -(-len(buf) // CHUNK))
+        manifest.append({"path": path, "shape": list(arr.shape), "dtype": str(arr.dtype),
+                         "chunks": n, "hash": h})
+        for ci in range(n):
+            store.db.put(store._chunk_key(step, path, ci), buf[ci * CHUNK : (ci + 1) * CHUNK])
+        hashes[path] = (h, step)
+    store._value_barrier()
+    meta = {"step": step, "time": 0.0, "manifest": manifest, "extra": {}, "reused_tensors": 0}
+    store.db.put(store._meta_key(step), msgpack.packb(meta, use_bin_type=True))
+    store.db.flush()
+    return hashes
+
+
+def test_incremental_reuse_over_tobytes_checkpoint(tmp_path):
+    """Hashes of a checkpoint written from ``tobytes`` buffers still match
+    the copy-free save's, so its unchanged leaves are reused."""
+    st = {"a": _leaf("float32"), "b": _leaf("bfloat16"), "t": _leaf("transposed"),
+          "step": np.int32(1)}
+    store = BVCheckpointStore(str(tmp_path / "ck"))
+    try:
+        old = _save_from_tobytes(store, 1, st)
+        st2 = {**st, "step": np.int32(2)}
+        store.save(2, st2, prev_hashes=old)
+        meta2 = store.load_meta(2)
+        reused = {e["path"] for e in meta2["manifest"] if e.get("reuse_step") == 1}
+        assert reused == {"['a']", "['b']", "['t']"}
+        written = {e["path"]: e for e in store.load_meta(1)["manifest"]}
+        for ent in meta2["manifest"]:
+            if ent["path"] in reused:  # the manifest entry as the old save wrote it
+                assert {k: v for k, v in ent.items() if k != "reuse_step"} == written[ent["path"]]
+        out, _ = store.load(2, template=st2)
+        for path, want in _leaf_paths(st2):
+            assert dict(_leaf_paths(out))[path].tobytes() == np.asarray(want).tobytes()
     finally:
         store.close()
 

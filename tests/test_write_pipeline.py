@@ -1,12 +1,14 @@
 """Write pipeline: WriteBatch semantics, leader/follower group commit,
 pipelined leader handoff (v2: overlap, sequence-ordered publication,
 adaptive group sizing, sharded memtable apply), BValue batched fan-out +
-roll race, MemTable sorted-view cache, and the BValue flush barrier."""
+roll race, MemTable sorted-view cache, the BValue flush barrier, and puts
+of byte buffers other than ``bytes``."""
 import os
 import threading
 import time
 from concurrent.futures import ThreadPoolExecutor
 
+import numpy as np
 import pytest
 
 from repro.core import DB, DBConfig, WriteBatch
@@ -595,6 +597,90 @@ def test_async_big_value_batch_unpins_after_persist(tmp_db_dir):
         assert db.bvcache.stats()["pinned"] == 0
         for i in range(0, 200, 23):
             assert db.get(f"big{i:03d}".encode()) == bytes([i % 251]) * 2048
+    finally:
+        db.close()
+
+
+def _cached_values(db) -> list:
+    with db.bvcache._lock:
+        return [e.value for e in (*db.bvcache._map.values(), *db.bvcache._pinned.values())]
+
+
+@pytest.mark.parametrize("wal", ["sync", "async"])
+@pytest.mark.parametrize("size", [4096, 64])  # separated / inline (threshold 512)
+def test_put_byte_buffer_reads_back_as_bytes(tmp_db_dir, wal, size):
+    """A memoryview put is read back as ``bytes`` before and after a flush
+    and after a reopen; overwriting the source array once ``put`` returned
+    changes neither, and the BVCache never holds the caller's buffer."""
+    src = np.arange(size, dtype=np.uint32).astype(np.uint8)
+    want = src.tobytes()
+    db = mk(tmp_db_dir, wal=wal)
+    try:
+        db.put(b"k", memoryview(src))
+        db.put(b"m", memoryview(src.reshape(-1, 8)))  # 2-D: taken flat
+        src[:] = 0xAB
+        for key in (b"k", b"m"):
+            got = db.get(key)
+            assert type(got) is bytes and got == want
+        assert all(type(v) is bytes for v in _cached_values(db))
+        db.flush()
+        for key in (b"k", b"m"):
+            got = db.get(key)
+            assert type(got) is bytes and got == want
+    finally:
+        db.close()
+    db = mk(tmp_db_dir, wal=wal)
+    try:
+        assert db.get(b"k") == want and db.get(b"m") == want
+    finally:
+        db.close()
+
+
+@pytest.mark.parametrize("kind", ["bytes", "memoryview"])
+def test_sync_big_put_pwrites_the_callers_buffer(tmp_db_dir, monkeypatch, kind):
+    """Under the sync WAL one big value reaches ``pwrite`` uncopied: a
+    ``bytes`` value as the same object (and into the BVCache, as before),
+    a memoryview as a view of the caller's memory (and not cached)."""
+    db = mk(tmp_db_dir, wal="sync")
+    src = np.arange(4096, dtype=np.uint32).astype(np.uint8)
+    value = src.tobytes() if kind == "bytes" else memoryview(src)
+    written = []
+    pwrite = db.bvalue.env.pwrite
+
+    def spy(fd, data, offset):
+        written.append(data)
+        return pwrite(fd, data, offset)
+
+    monkeypatch.setattr(db.bvalue.env, "pwrite", spy)
+    try:
+        db.put(b"k", value)
+        (data,) = written
+        if kind == "bytes":
+            assert data is value
+            assert _cached_values(db) == [value] and _cached_values(db)[0] is value
+        else:
+            assert np.shares_memory(np.frombuffer(data, np.uint8), src)
+            assert _cached_values(db) == []
+        assert db.get(b"k") == src.tobytes()
+    finally:
+        monkeypatch.undo()
+        db.close()
+
+
+@pytest.mark.parametrize("kind", ["float32", "strided", "str"])
+def test_put_refuses_a_value_that_is_no_byte_buffer(tmp_db_dir, kind):
+    value = {
+        "float32": lambda: memoryview(np.zeros(1024, np.float32)),
+        "strided": lambda: memoryview(np.zeros(4096, np.uint8)[::2]),
+        "str": lambda: "x" * 4096,
+    }[kind]()
+    db = mk(tmp_db_dir, wal="sync")
+    try:
+        with pytest.raises(TypeError):
+            db.put(b"k", value)
+        with pytest.raises(TypeError):
+            db.write(WriteBatch().put(b"k", value))
+        assert db.get(b"k") is None
     finally:
         db.close()
 
