@@ -1,4 +1,6 @@
-"""Async checkpoint manager: snapshots device state, hands the write to a
+"""Async checkpoint manager: snapshots device state to the host shard by
+shard (``BVCheckpointStore.snapshot``: each leaf's addressable shards, a
+replicated one once, never gathered whole), hands the write to a
 background thread (whose big values flow through the BValue multi-queue
 writers), keeps the last N checkpoints, and exposes a preemption hook —
 the trainer's SIGTERM handler calls ``save_now`` and the WAL-committed META
@@ -13,9 +15,7 @@ from __future__ import annotations
 import threading
 import time
 
-import jax
-
-from .bvstore import BVCheckpointStore
+from .bvstore import BVCheckpointStore, reuse_steps
 
 
 class CheckpointManager:
@@ -47,10 +47,10 @@ class CheckpointManager:
         return True
 
     def save_now(self, step: int, state, extra_meta: dict | None = None) -> None:
+        self.wait()  # one in-flight checkpoint at a time; wait() counts its stall
         t0 = time.monotonic()
-        self.wait()  # one in-flight checkpoint at a time
         with self.store.span("ckpt.snapshot", step=step):
-            host_state = jax.tree.map(lambda x: jax.device_get(x), state)
+            host_state = self.store.snapshot(state, step)
         snapshot_s = time.monotonic() - t0
 
         def _write():
@@ -80,8 +80,7 @@ class CheckpointManager:
         referenced = set()
         for s in keep:
             for ent in self.store.load_meta(s)["manifest"]:
-                if "reuse_step" in ent:
-                    referenced.add(ent["reuse_step"])
+                referenced |= reuse_steps(ent)
         for s in steps[: -self.keep_last]:
             if s not in referenced:
                 try:

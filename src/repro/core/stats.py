@@ -38,13 +38,13 @@ from collections import defaultdict
 
 # Every span the program records, by layer; parents before their children.
 SPAN_NAMES = (
-    # checkpoint manager, on the train loop's thread
-    "ckpt.wait", "ckpt.snapshot",
-    # checkpoint store, on the save thread: per leaf serialize/hash/put
+    # checkpoint manager, on the train loop's thread: per shard device-to-host
+    "ckpt.wait", "ckpt.snapshot", "ckpt.shard_snapshot",
+    # checkpoint store, on the save thread: per shard serialize/hash/put
     "ckpt.save", "ckpt.serialize", "ckpt.hash", "ckpt.put",
     "ckpt.barrier", "ckpt.commit",
-    # restore, on the loop's thread: per leaf read/join, then placement
-    "ckpt.restore", "ckpt.load_meta", "ckpt.read", "ckpt.join", "ckpt.place",
+    # restore, on the loop's thread: per chunk read/scatter, per leaf placement
+    "ckpt.restore", "ckpt.load_meta", "ckpt.read", "ckpt.scatter", "ckpt.place",
     # store engine: the big-value path only (a span costs a few µs, a
     # tenth of a small put or a cached get)
     "db.put", "wal.fsync",

@@ -229,7 +229,8 @@ def test_checkpoint_save_and_restore_spans(tmp_path, engine):
     host, meta = store.load(11, template=state)
     np.testing.assert_array_equal(host["params"]["w"], state["params"]["w"])
     loaded = _counts(store)
-    for name, count in [("ckpt.load_meta", 1), ("ckpt.read", leaves), ("ckpt.join", leaves)]:
+    chunks = leaves - 1 + big_chunks  # a read and a scatter per chunk
+    for name, count in [("ckpt.load_meta", 1), ("ckpt.read", chunks), ("ckpt.scatter", chunks)]:
         assert loaded[name] == count, name
     store.close()
     # reopened, with cold caches: each separated chunk (the 9 MiB leaf's
@@ -237,6 +238,40 @@ def test_checkpoint_save_and_restore_spans(tmp_path, engine):
     store = _open_store(tmp_path, engine)
     store.load(11, template=state)
     assert _counts(store)["bvalue.pread"] == big_chunks
+    store.close()
+
+
+@pytest.mark.parametrize("engine", ["db", "sharded"])
+def test_shard_snapshot_and_scatter_spans(tmp_path, engine):
+    """A device state is snapshotted with one ``ckpt.shard_snapshot`` per
+    shard inside ``ckpt.snapshot``; a restore onto a mesh reads and scatters
+    once per chunk, places once per leaf, and counts the bytes of each."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.checkpoint.manager import CheckpointManager
+    from repro.dist import Axes
+    from repro.launch.mesh import make_host_mesh
+
+    store = _open_store(tmp_path, engine)
+    state = jax.tree.map(jnp.asarray, _tiny_state())
+    nbytes = sum(x.nbytes for x in jax.tree.leaves(state))
+    CheckpointManager(store, async_save=False).save_now(1, state)
+    leaves, big_chunks = 3, 3
+    counts = _counts(store)
+    assert counts["ckpt.snapshot"] == 1 and counts["ckpt.shard_snapshot"] == leaves
+    spans = store.stats()["spans"]
+    assert spans["ckpt.shard_snapshot"]["seconds"] <= spans["ckpt.snapshot"]["seconds"]
+    assert store.stats()["ckpt_shards_saved"] == leaves
+    axes = {"params": {"w": Axes("param_embed", "mlp"), "b": Axes(None)}, "step": Axes()}
+    out, _ = store.load_distributed(make_host_mesh((1, 1)), state, axes)
+    np.testing.assert_array_equal(np.asarray(out["params"]["w"]), np.asarray(state["params"]["w"]))
+    chunks = leaves - 1 + big_chunks
+    counts = _counts(store)
+    for name, count in [("ckpt.read", chunks), ("ckpt.scatter", chunks), ("ckpt.place", leaves)]:
+        assert counts[name] == count, name
+    stats = store.stats()
+    assert stats["ckpt_read_bytes"] == nbytes and stats["ckpt_place_bytes"] == nbytes
     store.close()
 
 
@@ -258,10 +293,11 @@ def test_restore_spans_through_trainer(tmp_path):
     assert tr._init_or_restore() == 5
     spans = tr.store.stats()["spans"]
     tr.close()
+    # every leaf of this model is one chunk: a read and a scatter each
     for name, count in [("ckpt.restore", 1), ("ckpt.load_meta", 1), ("ckpt.read", n_leaves),
-                        ("ckpt.join", n_leaves), ("ckpt.place", 1)]:
+                        ("ckpt.scatter", n_leaves), ("ckpt.place", 1)]:
         assert spans[name]["count"] == count, name
     restore = spans["ckpt.restore"]
-    inside = sum(spans[k]["seconds"] for k in ("ckpt.load_meta", "ckpt.read", "ckpt.join", "ckpt.place"))
+    inside = sum(spans[k]["seconds"] for k in ("ckpt.load_meta", "ckpt.read", "ckpt.scatter", "ckpt.place"))
     assert inside <= restore["seconds"]
     assert restore["self_seconds"] == pytest.approx(restore["seconds"] - inside, abs=1e-6)
