@@ -30,8 +30,10 @@ Restore reads the newest META and reshards onto whatever mesh the restarted
 job has (elastic restart): for each target device it finds the saved shards
 that overlap the device's index, reads each chunk it needs once, and copies
 the chunk's overlap straight into a host buffer per device, which is then
-placed on its device; no leaf is built whole on the host. A restore with no
-mesh (``load``) is the case of one target: the whole leaf.
+placed on its device before the next leaf is read; no leaf is built whole
+on the host. A restore with no mesh (``load``) is the case of one target:
+the whole leaf, kept on the host or, with ``place``, placed on the default
+device the same way.
 
 Incremental mode skips shards whose index and content hash match the
 previous step's — LSM levels naturally hold the deltas and compaction
@@ -394,16 +396,39 @@ class BVCheckpointStore:
                               f"cover {filled[j]} of the {math.prod(_extent(b))} elements of {b}")
         return bufs
 
-    def load(self, step: int | None = None, template=None):
-        """Returns (state_pytree_of_np, meta). With `template`, the result
-        keeps its tree structure; otherwise a {path: array} dict. Each leaf
-        is read into one host array of its whole shape, whatever the shards
-        it was saved in."""
+    def _restore_leaf(self, step: int, ent: dict, targets: list, sharding=None):
+        """The leaf of manifest entry ``ent`` read (``_read_leaf``) into one
+        host buffer per ``(device, box)`` of ``targets``, each buffer placed
+        on its device (``ckpt.place``, ``ckpt_place_bytes``) before the next
+        leaf is read, so the transfer runs beside that read. With
+        ``sharding`` the leaf is assembled from the parts with
+        ``jax.make_array_from_single_device_arrays``; without, ``targets``
+        is the whole leaf on device ``None``, the default device,
+        uncommitted."""
+        path = ent["path"]
+        bufs = self._read_leaf(step, ent, [box for _, box in targets])
+        with self.span("ckpt.place", step=step, leaf=path):
+            parts = [jax.device_put(buf, d) for (d, _), buf in zip(targets, bufs)]
+            out = parts[0] if sharding is None else jax.make_array_from_single_device_arrays(
+                tuple(ent["shape"]), sharding, parts)
+        self._stats.add("ckpt_place_bytes", sum(b.nbytes for b in bufs))
+        return out
+
+    def load(self, step: int | None = None, template=None, place: bool = False):
+        """Returns (state_pytree, meta). With `template`, the result keeps
+        its tree structure; otherwise a {path: array} dict. Each leaf is
+        read into one host array of its whole shape, whatever the shards it
+        was saved in. A leaf is an ``np.ndarray``; with ``place``, a
+        ``jax.Array`` on the default device, placed as soon as it is read
+        (``_restore_leaf``)."""
         step = self._newest(step)
         with self.span("ckpt.load_meta", step=step):
             meta = self.load_meta(step)
-        arrays = {ent["path"]: self._read_leaf(step, ent, [_whole(ent["shape"])])[0]
-                  for ent in meta["manifest"]}
+        arrays = {}
+        for ent in meta["manifest"]:
+            box = _whole(ent["shape"])
+            arrays[ent["path"]] = (self._restore_leaf(step, ent, [(None, box)]) if place
+                                   else self._read_leaf(step, ent, [box])[0])
         if template is None:
             return arrays, meta
         flat, treedef = jax.tree_util.tree_flatten_with_path(template)
@@ -414,10 +439,9 @@ class BVCheckpointStore:
         """Elastic restore onto `mesh` (which may differ from the mesh the
         checkpoint was written on), under ``axes_tree``'s shardings: each
         leaf is read into one host buffer per addressable device (replicas
-        included), each buffer placed on its device (``ckpt.place``,
-        ``ckpt_place_bytes``) and the leaf assembled with
-        ``jax.make_array_from_single_device_arrays``. No leaf is built whole
-        on the host unless a device holds it whole."""
+        included), each buffer placed on its device and the leaf assembled
+        (``_restore_leaf``). No leaf is built whole on the host unless a
+        device holds it whole."""
         from repro.dist import tree_shardings
 
         step = self._newest(step)
@@ -434,12 +458,9 @@ class BVCheckpointStore:
             if tuple(ent["shape"]) != shape or np.dtype(ent["dtype"]) != np.dtype(sds.dtype):
                 raise ValueError(f"checkpoint @ step {step}: {path} is {ent['dtype']}"
                                  f"{ent['shape']}, the template's {sds.dtype}{list(shape)}")
-            devices = list(sharding.addressable_devices_indices_map(shape).items())
-            bufs = self._read_leaf(step, ent, [_box(idx, shape) for _, idx in devices])
-            with self.span("ckpt.place", step=step, leaf=path):
-                parts = [jax.device_put(buf, d) for (d, _), buf in zip(devices, bufs)]
-                out.append(jax.make_array_from_single_device_arrays(shape, sharding, parts))
-            self._stats.add("ckpt_place_bytes", sum(b.nbytes for b in bufs))
+            devices = sharding.addressable_devices_indices_map(shape).items()
+            targets = [(d, _box(idx, shape)) for d, idx in devices]
+            out.append(self._restore_leaf(step, ent, targets, sharding))
         return jax.tree_util.tree_unflatten(treedef, out), meta
 
     # ------------------------------------------------------------------

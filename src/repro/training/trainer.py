@@ -88,9 +88,7 @@ class Trainer:
                     axes = state_axes(self.model, self.tcfg.train.opt, template)
                     self.state, meta = self.store.load_distributed(self.mesh, template, axes, latest)
                 else:
-                    self.state, meta = self.store.load(latest, template=template)
-                    with self.store.span("ckpt.place", step=latest):  # dispatch; no wait
-                        self.state = jax.tree.map(jax.numpy.asarray, self.state)
+                    self.state, meta = self.store.load(latest, template=template, place=True)
                 self.pipeline.load_state_dict(meta["extra"]["pipeline"])
             return int(meta["step"])
         # under jit, so the state is built in place: sharded over the mesh

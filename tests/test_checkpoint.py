@@ -41,6 +41,69 @@ def test_save_load_roundtrip(tmp_path):
         store.close()
 
 
+def test_load_without_place_returns_host_arrays(tmp_path):
+    """``load`` without ``place`` gives host ``np.ndarray`` leaves, bit-equal
+    to the saved state, and places nothing."""
+    store = BVCheckpointStore(str(tmp_path / "ck"))
+    try:
+        st = _state()
+        store.save(10, st)
+        out, _ = store.load(10, template=st)
+        for got, want in zip(jax.tree.leaves(out), jax.tree.leaves(st)):
+            assert type(got) is np.ndarray
+            assert got.dtype == want.dtype and got.shape == want.shape
+            assert got.tobytes() == np.asarray(want).tobytes()
+        assert store.stats()["ckpt_place_bytes"] == 0
+    finally:
+        store.close()
+
+
+def test_meshless_restore_places_each_leaf_as_it_is_read(tmp_path, monkeypatch):
+    """A mesh-less ``Trainer`` restore puts each leaf on the device before
+    the next leaf's chunks are read, and restores device arrays bit-equal to
+    the saved state, with the template's dtypes and shapes."""
+    from repro.configs import get_config
+    from repro.training.trainer import Trainer, TrainerConfig
+
+    cfg = get_config("llama3-8b").reduced(d_model=64, n_layers=1, vocab=512, vocab_pad_multiple=64)
+    tcfg = TrainerConfig(global_batch=2, seq_len=16, ckpt_dir=str(tmp_path / "ck"))
+    tr = Trainer(cfg, tcfg)
+    tr._init_or_restore()
+    want = jax.tree.map(np.asarray, tr.state)
+    template = jax.eval_shape(lambda: tr.state)
+    tr.ckpt.save_now(5, tr.state, {"pipeline": tr.pipeline.state_dict()})
+    tr.close()
+
+    tr = Trainer(cfg, tcfg)
+    events = []  # ("get", leaf path) per chunk get, ("put", None) per device_put
+    db_get, device_put = tr.store.db.get, jax.device_put
+
+    def get(key, *a, **kw):
+        if key.startswith(b"ckpt/"):
+            events.append(("get", key.decode().split("/", 2)[2].rsplit("/", 1)[0]))
+        return db_get(key, *a, **kw)
+
+    def put(*a, **kw):
+        events.append(("put", None))
+        return device_put(*a, **kw)
+
+    monkeypatch.setattr(tr.store.db, "get", get)
+    monkeypatch.setattr(jax, "device_put", put)
+    try:
+        assert tr._init_or_restore() == 5
+    finally:
+        monkeypatch.undo()
+        tr.close()
+    gets = [leaf for kind, leaf in events if kind == "get"]
+    assert len(set(gets)) == len(jax.tree.leaves(want)) > 1
+    first_put = events.index(("put", None))
+    assert first_put < events.index(("get", gets[-1]))
+    for got, w, t in zip(jax.tree.leaves(tr.state), jax.tree.leaves(want), jax.tree.leaves(template)):
+        assert isinstance(got, jax.Array)
+        assert got.dtype == t.dtype and got.shape == t.shape
+        assert np.asarray(got).tobytes() == w.tobytes()
+
+
 def _leaf(case: str) -> np.ndarray:
     """A leaf of more than one chunk, in each form a save has to take."""
     a = np.arange(CHUNK // 4 + 3000, dtype=np.float32)

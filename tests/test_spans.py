@@ -291,12 +291,14 @@ def test_restore_spans_through_trainer(tmp_path):
 
     tr = Trainer(cfg, tcfg)
     assert tr._init_or_restore() == 5
-    spans = tr.store.stats()["spans"]
+    stats = tr.store.stats()
+    spans = stats["spans"]
     tr.close()
-    # every leaf of this model is one chunk: a read and a scatter each
+    # every leaf of this model is one chunk: a read, a scatter and a place each
     for name, count in [("ckpt.restore", 1), ("ckpt.load_meta", 1), ("ckpt.read", n_leaves),
-                        ("ckpt.scatter", n_leaves), ("ckpt.place", 1)]:
+                        ("ckpt.scatter", n_leaves), ("ckpt.place", n_leaves)]:
         assert spans[name]["count"] == count, name
+    assert stats["ckpt_place_bytes"] == sum(x.nbytes for x in jax.tree.leaves(tr.state))
     restore = spans["ckpt.restore"]
     inside = sum(spans[k]["seconds"] for k in ("ckpt.load_meta", "ckpt.read", "ckpt.scatter", "ckpt.place"))
     assert inside <= restore["seconds"]
